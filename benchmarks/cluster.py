@@ -248,7 +248,10 @@ def mesh_routing(device_counts=(1, 2, 4), n_shards: int = 4,
                  batch: int = 512) -> dict:
     """Fused vs host dispatch at forced host-device counts (subprocesses:
     the device count is fixed at jax init). At 1 device the plan gates the
-    fusion off, so both arms measure the host path — the honest baseline."""
+    fusion off, so both arms measure the host path — the honest baseline.
+
+    CPU-only rehearsal: the probes force `JAX_PLATFORMS=cpu` virtual
+    devices, so these rows time XLA's CPU backend, never a chip."""
     out = {}
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     for ndev in device_counts:

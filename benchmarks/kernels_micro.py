@@ -123,7 +123,10 @@ print(json.dumps(rows))
 def profile_mesh(ndev: int = 4) -> list[dict]:
     """The same profile inside a forced-`ndev`-device mesh subprocess —
     partition_gain resolves to the owner-local shard_map fusion there, so
-    its rows land under path="mesh"."""
+    its rows land under path="mesh".
+
+    CPU-only rehearsal: the probe forces `JAX_PLATFORMS=cpu` virtual
+    devices, so these rows time XLA's CPU backend, never a chip."""
     root = os.path.join(os.path.dirname(__file__), "..")
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}",

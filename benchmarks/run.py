@@ -16,7 +16,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 SECTIONS = ("kernels", "solvers", "parallel", "generalization", "stream",
             "cluster", "ingest", "frontend", "roofline")

@@ -53,9 +53,13 @@ class TieringPipeline:
 
     # -- constructors --------------------------------------------------------
     @classmethod
-    def from_synthetic(cls, seed: int = 0, scale: str = "tiny") -> "TieringPipeline":
+    def from_synthetic(cls, seed: int = 0, scale: str = "tiny", *,
+                       n_docs: int | None = None) -> "TieringPipeline":
+        """Seeded synthetic corpus + query log (`data.synthetic` presets);
+        `n_docs` overrides the preset's document count."""
         from repro.data import synthetic
-        corpus, log = synthetic.make_tiering_dataset(seed, scale)
+        corpus, log = synthetic.make_tiering_dataset(seed, scale,
+                                                     n_docs=n_docs)
         return cls(corpus, log)
 
     @classmethod

@@ -75,7 +75,42 @@ class MeshRouteTable:
     vocab_size: int
 
 
-def build_table(buf, n_devices: int, *, use_t1: bool = True) -> MeshRouteTable:
+def _shards_per_device(n_shards: int, n_devices: int) -> int:
+    return -(-n_shards // n_devices)
+
+
+def shard_device(plan, shard_index: int, n_shards: int):
+    """The device whose block of the fused route table holds shard
+    `shard_index` (None off a multi-device `"shard"` mesh). The table is
+    sharded by leading-axis blocks of `ceil(n_shards / n_devices)` shards."""
+    if not plan.shard_fused:
+        return None
+    pos = shard_index // _shards_per_device(n_shards, plan.n_shard_devices)
+    axis = plan.mesh.axis_names.index(plan.shard_axis)
+    return np.moveaxis(plan.mesh.devices, axis, 0)[pos:pos + 1].flat[0]
+
+
+def _tier_block(buf, lo: int, hi: int, v: int, wmax: int, use_t1: bool,
+                device) -> jnp.ndarray:
+    """uint32 [hi - lo, 2, V, wmax]: stacked (Tier-2, Tier-1) slices of
+    table rows [lo, hi), built on `device` from the buffer's own arrays."""
+    def place(x):
+        if x is None:
+            return jnp.zeros((v, wmax), jnp.uint32, device=device)
+        x = jax.device_put(x, device)
+        return x if x.shape[1] == wmax else \
+            jnp.pad(x, ((0, 0), (0, wmax - x.shape[1])))
+
+    rows = []
+    for i in range(lo, hi):
+        s = buf.shards[i] if i < len(buf.shards) else None   # pad shard
+        rows.append(place(None if s is None else buf.t2_postings[s.index]))
+        rows.append(place(buf.shard_postings[s.index]
+                          if s is not None and use_t1 else None))
+    return jnp.stack(rows).reshape(hi - lo, 2, v, wmax)
+
+
+def build_table(buf, plan, *, use_t1: bool = True) -> MeshRouteTable:
     """Stack per-shard resident slices for the fused program.
 
     Every operand comes from ONE `ClusterTieringBuffer`: its Tier-1
@@ -85,37 +120,47 @@ def build_table(buf, n_devices: int, *, use_t1: bool = True) -> MeshRouteTable:
     With `use_t1=False` (the mid-rollout gap, served entirely at the
     buffer's corpus version) the ψ clause set is empty and every query
     routes to the buffer's Tier-2 slices, still one fused dispatch.
+
+    Each device's block is stacked on that device from the slices placed
+    there (`shard_device`), so no device ever holds the whole index.
     """
+    from jax.sharding import NamedSharding
+
     shards = buf.shards
+    mesh, axis = plan.mesh, plan.shard_axis
     vocab_size = buf.tiering.vocab_size
     wmax = max(s.n_words for s in shards)
-    s_pad = -len(shards) % n_devices
-    v = int(np.asarray(buf.t2_postings[0]).shape[0])
-    tiers_l, off, wid, t1w = [], [], [], []
+    s_all = _shards_per_device(len(shards), plan.n_shard_devices) \
+        * plan.n_shard_devices
+    v = int(buf.t2_postings[0].shape[0])
+    off, wid, t1w = [], [], []
     for s in shards:
-        pad = ((0, 0), (0, wmax - s.n_words))
-        t2 = np.pad(np.asarray(buf.t2_postings[s.index]), pad)
-        if use_t1:
-            t1 = np.pad(np.asarray(buf.shard_postings[s.index]), pad)
-            t1w.append(buf.shard_words[s.index])
-        else:
-            t1 = np.zeros((v, wmax), np.uint32)
-            t1w.append(0)
-        tiers_l.append(np.stack([t2, t1]))           # [2, V, wmax]
+        t1w.append(buf.shard_words[s.index] if use_t1 else 0)
         off.append(s.word_lo)
         wid.append(s.n_words)
-    for _ in range(s_pad):          # pad shards: zero words, scratch offset
-        tiers_l.append(np.zeros((2, v, wmax), np.uint32))
-        off.append(buf.w_total)
+    for _ in range(s_all - len(shards)):   # pad shards: zero words, scratch
+        off.append(buf.w_total)            # offset past the real index
         wid.append(0)
         t1w.append(0)
+    shape = (s_all, 2, v, wmax)
+    sharded = NamedSharding(mesh, P(axis))
+    blocks = [_tier_block(buf, idx[0].start or 0, idx[0].stop or s_all, v,
+                          wmax, use_t1, dev)
+              for dev, idx in
+              sharded.addressable_devices_indices_map(shape).items()]
     cbits = buf.tiering.clause_vocab_bits if use_t1 else \
         np.zeros((0, max(1, -(-vocab_size // 32))), np.uint32)
+
+    def put(x, spec):
+        return jax.device_put(np.asarray(x), NamedSharding(mesh, spec))
+
     return MeshRouteTable(
-        clause_bits=jnp.asarray(cbits),
-        tiers=jnp.asarray(np.stack(tiers_l)),
-        off=jnp.asarray(off, jnp.int32), wid=jnp.asarray(wid, jnp.int32),
-        t1w=jnp.asarray(t1w, jnp.int32),
+        clause_bits=put(cbits, P()),
+        tiers=jax.make_array_from_single_device_arrays(shape, sharded,
+                                                       blocks),
+        off=put(np.asarray(off, np.int32), P(axis)),
+        wid=put(np.asarray(wid, np.int32), P(axis)),
+        t1w=put(np.asarray(t1w, np.int32), P(axis)),
         w_total=buf.w_total, wmax=wmax, vocab_size=vocab_size)
 
 

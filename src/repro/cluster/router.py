@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -431,8 +432,7 @@ class ClusterRouter:
                len(buf.shards or self.shards))
         table = self._mesh_tables.get(key)
         if table is None:
-            table = mesh_serve.build_table(buf, plan.n_shard_devices,
-                                           use_t1=use_t1)
+            table = mesh_serve.build_table(buf, plan, use_t1=use_t1)
             if len(self._mesh_tables) > 8:
                 self._mesh_tables.clear()
             self._mesh_tables[key] = table
@@ -542,11 +542,12 @@ class TieredCluster:
         self.n_docs = n_docs
         self.corpus_version = 0
         self._postings_host = np.asarray(postings)
-        self.postings_t2 = jnp.asarray(postings)          # oracle index
+        self._oracle = None
         self.shards, self._slices = shard_mod.shard_postings(
             self._postings_host, n_docs, n_shards)
         self._content_seq = 0
-        self._t2_dev = [jnp.asarray(sl) for sl in self._slices]
+        self._t2_dev = [self._place(sl, i)
+                        for i, sl in enumerate(self._slices)]
         self._t2_content = tuple(self._next_content() for _ in self.shards)
         buf0 = self._build_buffer(tiering, generation=0)
         t1 = [[ShardReplica(1, s, buf0.shard_postings[s.index],
@@ -563,6 +564,25 @@ class TieredCluster:
     def _next_content(self) -> int:
         self._content_seq += 1
         return self._content_seq
+
+    def _place(self, arr, shard_index: int):
+        """Put one shard's sub-index on the device that serves it: under a
+        `"shard"` mesh that is the device holding the shard's slice of the
+        fused route table, so no device carries another shard's bits."""
+        from repro.cluster import mesh_serve
+        from repro import distributed
+        dev = mesh_serve.shard_device(distributed.current_plan(),
+                                      shard_index, len(self.shards))
+        return jnp.asarray(arr) if dev is None else jax.device_put(arr, dev)
+
+    @property
+    def postings_t2(self):
+        """Full-width oracle index (`serve_reference`), put on the device on
+        first use: at a chip's real index size the shard slices the fleet
+        serves from already fill it."""
+        if self._oracle is None:
+            self._oracle = jnp.asarray(self._postings_host)
+        return self._oracle
 
     def _shard_t1(self, tiering: ClauseTiering, s) -> np.ndarray:
         return np.asarray(tiering.tier1_docs[s.doc_lo:s.doc_lo + s.n_docs],
@@ -587,7 +607,7 @@ class TieredCluster:
         for s in self.shards:
             p, w = shard_mod.shard_tier_postings(
                 self._slices[s.index], s, tiering.tier1_docs)
-            posts.append(jnp.asarray(p))
+            posts.append(self._place(p, s.index))
             words.append(w)
             if prev is not None and np.array_equal(
                     self._shard_t1(tiering, s),
@@ -658,8 +678,9 @@ class TieredCluster:
                         f"no live buffer at corpus version {corpus_version}; "
                         f"live: {sorted({b.corpus_version for b in bufs.values()})}")
                 buf = max(cands, key=lambda b: b.generation)
-            postings = buf.t2_postings[0] if len(buf.t2_postings) == 1 \
-                else jnp.concatenate(buf.t2_postings, axis=1)
+            # shard slices may sit on different devices: join on the host
+            postings = np.concatenate([np.asarray(p) for p in buf.t2_postings],
+                                      axis=1)
             n_docs = buf.n_docs
         toks = matching.pad_token_batch(queries)
         m = np.asarray(matching.match_batch(postings, jnp.asarray(toks)))
@@ -722,9 +743,9 @@ class TieredCluster:
                 dev.append(self._t2_dev[s.index])
             else:
                 contents.append(self._next_content())
-                dev.append(jnp.asarray(new_slices[s.index]))
+                dev.append(self._place(new_slices[s.index], s.index))
         self._postings_host = postings
-        self.postings_t2 = jnp.asarray(postings)
+        self._oracle = None
         self.shards = new_shards
         self._slices = new_slices
         self._t2_dev = dev

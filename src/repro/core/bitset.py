@@ -40,6 +40,32 @@ def np_pack(bits: np.ndarray) -> np.ndarray:
     return (padded.astype(np.uint32) * weights).sum(axis=-1, dtype=np.uint32)
 
 
+def np_pack_pairs(rows: np.ndarray, cols: np.ndarray, n_rows: int,
+                  n_bits: int) -> np.ndarray:
+    """uint32 [n_rows, ceil(n_bits/32)] with bit `cols[i]` of row `rows[i]`
+    set — `np_pack` of the sparse bool matrix, never materialising it."""
+    rows = np.asarray(rows, np.int64)
+    cols = np.asarray(cols, np.int64)
+    w = n_words(n_bits)
+    out = np.zeros(n_rows * w, np.uint32)
+    np.bitwise_or.at(out, rows * w + (cols >> 5),
+                     np.uint32(1) << (cols & 31).astype(np.uint32))
+    return out.reshape(n_rows, w)
+
+
+def np_pack_sets(sets, n_bits: int, *, transpose: bool = False) -> np.ndarray:
+    """Packed rows [len(sets), ceil(n_bits/32)] from id collections; with
+    `transpose`, the inverted index [n_bits, ceil(len(sets)/32)] whose row
+    v has bit i set iff v ∈ sets[i]."""
+    lengths = [len(s) for s in sets]
+    idx = np.repeat(np.arange(len(sets)), lengths)
+    ids = np.fromiter((int(v) for s in sets for v in s), np.int64,
+                      count=sum(lengths))
+    if transpose:
+        return np_pack_pairs(ids, idx, n_bits, len(sets))
+    return np_pack_pairs(idx, ids, len(sets), n_bits)
+
+
 def np_unpack(words: np.ndarray, n_bits: int) -> np.ndarray:
     """Unpack uint32 words [..., W] back to bool [..., n_bits]."""
     words = np.asarray(words, dtype=np.uint32)

@@ -25,53 +25,52 @@ from repro.data.synthetic import Corpus, QueryLog
 
 
 def build_postings(corpus: Corpus) -> np.ndarray:
-    """Packed postings lists: bit d of row v set iff v ∈ doc d."""
-    n_docs = corpus.n_docs
-    bits = np.zeros((corpus.vocab_size, n_docs), dtype=bool)
-    for d, toks in enumerate(corpus.doc_tokens):
-        bits[list(toks), d] = True
-    return bitset.np_pack(bits)
+    """Packed postings lists: bit d of row v set iff v ∈ doc d. Packed
+    straight from the (term, doc) pairs, so no [V, n_docs] bool plane."""
+    return bitset.np_pack_sets(corpus.doc_tokens, corpus.vocab_size,
+                               transpose=True)
 
 
 def match_bits(postings: np.ndarray, clause: tuple[int, ...], n_docs: int) -> np.ndarray:
     """m(clause) as a packed bitset: AND of the clause terms' postings."""
-    out = np.full(postings.shape[1], 0xFFFFFFFF, dtype=np.uint32)
-    for t in clause:
-        out &= postings[t]
-    # clear padding bits beyond n_docs
-    pad_mask = bitset.np_pack(np.ones(n_docs, dtype=bool))
-    return out & pad_mask
+    return _and_rows(postings, [clause], n_docs)[0]
+
+
+def _and_rows(postings: np.ndarray, sets: list[tuple[int, ...]],
+              n_bits: int) -> np.ndarray:
+    """[len(sets), W]: AND of each set's postings rows, padding bits beyond
+    `n_bits` cleared (an empty set matches every valid bit)."""
+    pad_mask = bitset.np_pack(np.ones(n_bits, dtype=bool))
+    out = np.empty((len(sets), postings.shape[1]), np.uint32)
+    for i, s in enumerate(sets):
+        row = pad_mask.copy()
+        for t in s:
+            row &= postings[t]
+        out[i] = row
+    return out
 
 
 def clause_doc_incidence(postings: np.ndarray, clauses: list[tuple[int, ...]],
                          n_docs: int) -> np.ndarray:
-    return np.stack([match_bits(postings, c, n_docs) for c in clauses]) \
-        if clauses else np.zeros((0, postings.shape[1]), np.uint32)
+    return _and_rows(postings, clauses, n_docs)
 
 
 def clause_query_incidence(
     query_bits: np.ndarray,            # packed [Nq, Wv]
     clauses: list[tuple[int, ...]],
     vocab_size: int,
-    chunk: int = 512,
 ) -> np.ndarray:
-    """Packed [C, Wq]: bit q of row c set iff c ⊆ q. Chunked subset test."""
+    """Packed [C, Wq]: bit q of row c set iff c ⊆ q — the AND of the
+    clause terms' rows of the query-side inverted index."""
     nq = query_bits.shape[0]
-    cbits = np.zeros((len(clauses), vocab_size), dtype=bool)
-    for i, c in enumerate(clauses):
-        cbits[i, list(c)] = True
-    cpk = bitset.np_pack(cbits)                       # [C, Wv]
-    out = np.zeros((len(clauses), nq), dtype=bool)
-    for s in range(0, len(clauses), chunk):
-        blk = cpk[s:s + chunk]                        # [b, Wv]
-        sub = (query_bits[None, :, :] & blk[:, None, :]) == blk[:, None, :]
-        out[s:s + chunk] = sub.all(axis=-1)
-    return bitset.np_pack(out)
+    q_of_term = bitset.np_pack(
+        bitset.np_unpack(query_bits, vocab_size).T)   # [V, Wq]
+    return _and_rows(q_of_term, clauses, nq)
 
 
 def query_doc_incidence(postings: np.ndarray, log: QueryLog, n_docs: int) -> np.ndarray:
     """m(q) per unique query, packed [Nq, Wd] (used by flow baselines)."""
-    return np.stack([match_bits(postings, q, n_docs) for q in log.queries])
+    return _and_rows(postings, log.queries, n_docs)
 
 
 def padded_id_lists(rows_bits: np.ndarray, n_bits: int,
@@ -159,14 +158,13 @@ def append_docs(data: "TieringData", docs: list[tuple[int, ...]]) -> AppendDelta
 
     # block postings [V, wb]: bit (d - doc_lo) of row v set iff v ∈ doc d
     wb = word_hi - word_lo
-    blk = np.zeros((corpus.vocab_size, n_new), dtype=bool)
-    for j, toks in enumerate(corpus.doc_tokens[doc_lo:]):
-        blk[list(toks), j] = True
-    blk_postings = bitset.np_pack(blk)       # [V, wb]: doc_lo is word-aligned
-
+    new_docs = corpus.doc_tokens[doc_lo:]
+    # doc_lo is word-aligned, so block-local ids pack into whole words
+    blk_postings = bitset.np_pack_sets(new_docs, corpus.vocab_size,
+                                       transpose=True)
     # corpus doc_bits rows: holes are all-zero rows, then the packed docs
     hole_rows = np.zeros((n_holes, corpus.doc_bits.shape[1]), np.uint32)
-    doc_rows = bitset.np_pack(blk.T)
+    doc_rows = bitset.np_pack_sets(new_docs, corpus.vocab_size)
     corpus.doc_bits = np.concatenate([corpus.doc_bits, hole_rows, doc_rows])
 
     # incidence columns over the block only (block doc ids are local)

@@ -59,6 +59,62 @@ def _zipf_probs(n: int, a: float) -> np.ndarray:
     return p / p.sum()
 
 
+def _choice_terms(rng: np.random.Generator, probs: np.ndarray,
+                  lengths: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """(doc, term) pairs, one `rng.choice(replace=False, p=probs)` per doc.
+
+    The RNG stream the small presets were generated with; each draw is O(V).
+    """
+    vocab_size = len(probs)
+    terms = [np.unique(rng.choice(vocab_size, size=int(min(k, vocab_size)),
+                                  replace=False, p=probs)) for k in lengths]
+    doc = np.repeat(np.arange(len(terms)), [len(t) for t in terms])
+    return doc, _concat(terms)
+
+
+def _bulk_terms(rng: np.random.Generator, probs: np.ndarray,
+                lengths: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """(doc, term) pairs with the law of `_choice_terms`, drawn in bulk.
+
+    `rng.choice(replace=False, p=...)` draws with replacement, keeps the
+    distinct terms and redraws the shortfall: each doc gets the first k
+    distinct terms of an iid stream from `probs`. Here every doc that is
+    still short draws its shortfall in one vectorised round, so the cost is
+    O(pairs · log V) rather than O(V) per doc.
+    """
+    vocab_size = len(probs)
+    cdf = np.cumsum(probs)
+    cdf /= cdf[-1]
+    need = np.minimum(lengths, vocab_size).astype(np.int64)
+    found: list[np.ndarray] = []
+    pending = np.zeros(0, np.int64)     # accepted keys of docs still short
+    active = np.arange(len(need))
+    while active.size:
+        doc = np.repeat(active, need[active])
+        term = np.searchsorted(cdf, rng.random(doc.size), side="right")
+        keys = doc * vocab_size + term
+        fresh = np.unique(keys[~np.isin(keys, pending)])
+        found.append(fresh)
+        need -= np.bincount(fresh // vocab_size, minlength=len(need))
+        pending = np.concatenate([pending, fresh])
+        pending = pending[need[pending // vocab_size] > 0]
+        active = active[need[active] > 0]
+    keys = np.sort(_concat(found))
+    return keys // vocab_size, keys % vocab_size
+
+
+def _concat(parts: list[np.ndarray]) -> np.ndarray:
+    return np.concatenate(parts) if parts else np.zeros(0, np.int64)
+
+
+def _pairs_to_tokens(doc: np.ndarray, term: np.ndarray,
+                     n_docs: int) -> list[tuple[int, ...]]:
+    """(doc, term) pairs sorted by doc then term -> sorted term tuples."""
+    ptr = np.searchsorted(doc, np.arange(n_docs + 1))
+    flat = term.tolist()
+    return [tuple(flat[a:b]) for a, b in zip(ptr[:-1], ptr[1:])]
+
+
 def make_corpus(
     rng: np.random.Generator,
     *,
@@ -66,21 +122,21 @@ def make_corpus(
     n_docs: int = 20000,
     doc_len_mean: float = 8.0,
     zipf_a: float = 1.05,
+    bulk: bool = False,
 ) -> Corpus:
+    """Zipfian term-set documents. `bulk` draws every doc's terms in
+    vectorised rounds (same law, different RNG stream) — what an index of
+    millions of documents needs; the default keeps the per-doc stream the
+    small presets were generated with."""
     probs = _zipf_probs(vocab_size, zipf_a)
     # shuffle so token id is not rank (more realistic hashing)
     perm = rng.permutation(vocab_size)
     probs = probs[perm]
-    docs: list[tuple[int, ...]] = []
     lengths = np.maximum(2, rng.poisson(doc_len_mean, size=n_docs))
-    for i in range(n_docs):
-        k = int(min(lengths[i], vocab_size))
-        toks = rng.choice(vocab_size, size=k, replace=False, p=probs)
-        docs.append(tuple(sorted(int(t) for t in set(toks.tolist()))))
-    bits = np.zeros((n_docs, vocab_size), dtype=bool)
-    for i, d in enumerate(docs):
-        bits[i, list(d)] = True
-    return Corpus(doc_tokens=docs, doc_bits=bitset.np_pack(bits), vocab_size=vocab_size)
+    doc, term = (_bulk_terms if bulk else _choice_terms)(rng, probs, lengths)
+    return Corpus(doc_tokens=_pairs_to_tokens(doc, term, n_docs),
+                  doc_bits=bitset.np_pack_pairs(doc, term, n_docs, vocab_size),
+                  vocab_size=vocab_size)
 
 
 def make_query_log(
@@ -121,13 +177,9 @@ def make_query_log(
     train_counts = train_counts[keep]
     test_counts = test_counts[keep]
 
-    bits = np.zeros((len(queries), corpus.vocab_size), dtype=bool)
-    for i, q in enumerate(queries):
-        bits[i, list(q)] = True
-
     return QueryLog(
         queries=queries,
-        query_bits=bitset.np_pack(bits),
+        query_bits=bitset.np_pack_sets(queries, corpus.vocab_size),
         train_weights=train_counts / max(1, n_train),
         test_weights=test_counts / max(1, n_test),
         n_train_samples=n_train,
@@ -135,21 +187,36 @@ def make_query_log(
     )
 
 
-def make_tiering_dataset(seed: int = 0, scale: str = "small"):
+_MEDIUM = dict(vocab_size=2000, n_docs=20000, doc_len_mean=8.0,
+               pool=30000, n_train=200000, n_test=70000)
+# One chip's quarter of the MS MARCO passage-ranking collection (8,841,823
+# passages): 2^21 docs over 8,192 dense-bitset head terms (an assumed head:
+# tail terms need hybrid postings), 8,192 unique queries, and medium's
+# document length and train/test draws.
+_PASSAGE = dict(_MEDIUM, vocab_size=8192, n_docs=2**21, pool=8192, bulk=True)
+
+PRESETS = {
+    "tiny": dict(vocab_size=64, n_docs=200, doc_len_mean=6.0,
+                 pool=400, n_train=4000, n_test=1500),
+    "small": dict(vocab_size=800, n_docs=4000, doc_len_mean=8.0,
+                  pool=6000, n_train=60000, n_test=20000),
+    "medium": _MEDIUM,
+    "passage": _PASSAGE,
+}
+
+
+def make_tiering_dataset(seed: int = 0, scale: str = "small", *,
+                         n_docs: int | None = None):
     """One-call dataset factory. Scales: tiny (tests), small (benches),
-    medium (solver benchmarks)."""
+    medium (solver benchmarks), passage (one chip's index). `n_docs`
+    overrides the preset's document count, e.g. four chips' passage
+    shards in one index."""
     rng = np.random.default_rng(seed)
-    presets = {
-        "tiny": dict(vocab_size=64, n_docs=200, doc_len_mean=6.0,
-                     pool=400, n_train=4000, n_test=1500),
-        "small": dict(vocab_size=800, n_docs=4000, doc_len_mean=8.0,
-                      pool=6000, n_train=60000, n_test=20000),
-        "medium": dict(vocab_size=2000, n_docs=20000, doc_len_mean=8.0,
-                       pool=30000, n_train=200000, n_test=70000),
-    }
-    p = presets[scale]
-    corpus = make_corpus(rng, vocab_size=p["vocab_size"], n_docs=p["n_docs"],
-                         doc_len_mean=p["doc_len_mean"])
+    p = PRESETS[scale]
+    corpus = make_corpus(rng, vocab_size=p["vocab_size"],
+                         n_docs=p["n_docs"] if n_docs is None else n_docs,
+                         doc_len_mean=p["doc_len_mean"],
+                         bulk=p.get("bulk", False))
     log = make_query_log(rng, corpus, pool_size=p["pool"],
                          n_train=p["n_train"], n_test=p["n_test"])
     return corpus, log

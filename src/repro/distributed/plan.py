@@ -46,23 +46,11 @@ from repro.distributed import mesh_context
 BACKENDS = ("pallas", "interpret", "xla")
 SHARD_AXIS = "shard"
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.7
 
-    def shard_map(f, mesh, in_specs, out_specs, **kw):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _sm_old
 
-    def shard_map(f, mesh, in_specs, out_specs, **kw):
-        # old API spells replication checking `check_rep`; same semantics
-        # (the ring OR-merge's replicated-by-construction outputs defeat the
-        # static inference either way, so the flag must actually map through)
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       **kw)
+def shard_map(f, mesh, in_specs, out_specs, **kw):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 # -- backend resolution --------------------------------------------------------

@@ -14,6 +14,11 @@ Cache resolution order:
 - ``REPRO_KERNEL_TILES=/path.json``  → explicit cache file.
 - unset                              → ``artifacts/autotune/tiles.json``.
 
+A cache applies only on the backend that timed it (its recorded
+``"backend"``): the file is gitignored and tuned per host, so a leftover
+CPU-timed copy never changes which tiles a chip runs — there the kernels
+keep the defaults written in code.
+
 The search itself (`search` / `ensure_cache`, also exposed as
 ``python -m repro.kernels.autotune``) is deterministic by construction: data
 is synthesized from a fixed seed, candidates are enumerated in a fixed order,
@@ -124,24 +129,27 @@ def bucket_from_args(op: str, args: Sequence[Any]):
 
 
 @functools.lru_cache(maxsize=8)
-def _load_entries(path: str) -> Dict[str, Dict[str, Any]]:
+def _load_entries(path: str, backend: str) -> Dict[str, Dict[str, Any]]:
     try:
         with open(path) as fh:
             blob = json.load(fh)
     except (OSError, ValueError):
         return {}
-    if not isinstance(blob, dict) or blob.get("version") != CACHE_VERSION:
+    if not isinstance(blob, dict) or blob.get("version") != CACHE_VERSION \
+            or blob.get("backend") != backend:
         return {}
     entries = blob.get("entries", {})
     return entries if isinstance(entries, dict) else {}
 
 
 @functools.lru_cache(maxsize=4096)
-def _tile_params_cached(env_raw, op: str, path: str, shape_bucket: str):
+def _tile_params_cached(env_raw, backend: str, op: str, path: str,
+                        shape_bucket: str):
     if env_raw is not None and env_raw.strip().lower() in _DISABLED:
         return {}
     cache_path = env_raw if env_raw else DEFAULT_CACHE
-    got = _load_entries(cache_path).get(f"{op}|{path}|{shape_bucket}")
+    got = _load_entries(cache_path, backend).get(
+        f"{op}|{path}|{shape_bucket}")
     if not isinstance(got, dict):
         return {}
     # Drop bookkeeping keys; whatever remains is kwargs for the kernel impl.
@@ -152,7 +160,10 @@ def tile_params(op: str, path: str, shape_bucket) -> Dict[str, Any]:
     """Tuned kwargs for (op, path, bucket); {} on miss or when disabled."""
     if shape_bucket is None:
         return {}
-    return dict(_tile_params_cached(os.environ.get(ENV_VAR), op, path, shape_bucket))
+    import jax
+    return dict(_tile_params_cached(os.environ.get(ENV_VAR),
+                                    jax.default_backend(), op, path,
+                                    shape_bucket))
 
 
 def invalidate() -> None:
@@ -311,8 +322,9 @@ def ensure_cache(*, seed: int = 0) -> Tuple[str, int]:
     raw = os.environ.get(ENV_VAR)
     if raw is not None and raw.strip().lower() in _DISABLED:
         return ("<disabled>", 0)
+    import jax
     path = cache_path()
-    entries = _load_entries(path)
+    entries = _load_entries(path, jax.default_backend())
     if entries:
         return (path, len(entries))
     blob = search(seed=seed, out=path)

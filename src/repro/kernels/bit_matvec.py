@@ -31,6 +31,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.tiles import block_dim
 
 WORD = 32
+_LANE = 128
 
 
 def _kernel(a_hbm, x_hbm, o_ref, a_buf, x_buf, sem_a, sem_x, *,
@@ -68,8 +69,11 @@ def _kernel(a_hbm, x_hbm, o_ref, a_buf, x_buf, sem_a, sem_x, *,
         a = a_buf[slot]                              # [BC, BW] uint32
         shifts = jnp.arange(WORD, dtype=jnp.uint32)
         bits = (a[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)
-        bits = bits.reshape(a.shape[0], -1).astype(jnp.float32)   # [BC, BW*32]
+        # Mosaic has no uint32 -> f32 convert; the bits fit int32 exactly
+        bits = bits.reshape(a.shape[0], -1).astype(jnp.int32) \
+            .astype(jnp.float32)                     # [BC, BW*32]
         return acc + jnp.dot(bits, x_buf[slot],
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
 
     init = jnp.zeros(o_ref.shape, jnp.float32)
@@ -91,24 +95,28 @@ def bit_matvec(
     # pad to tile multiples; zero words / zero x rows contribute nothing.
     bc, cp, nc = block_dim(c, block_c)
     bw, wp, nw = block_dim(w, block_w)
-    if cp or wp:
+    # the x slab and the [BC, R] result tile sit on the 128-lane axis, so R
+    # pads up to a lane multiple (a matvec's R=1 included); zero columns
+    # are sliced off below
+    rp = -r % _LANE
+    if cp or wp or rp:
         a_bits = jnp.pad(a_bits, ((0, cp), (0, wp)))
-        x = jnp.pad(x, ((0, wp * WORD), (0, 0)))
+        x = jnp.pad(x, ((0, wp * WORD), (0, rp)))
     out = pl.pallas_call(
         functools.partial(_kernel, block_c=bc, block_w=bw, n_w=nw),
         grid=(nc,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),    # streamed by the kernel
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),    # streamed by the kernel
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((bc, r), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(((c + cp), r), jnp.float32),
+        out_specs=pl.BlockSpec((bc, r + rp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((c + cp, r + rp), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((2, bc, bw), jnp.uint32),     # packed A slots
-            pltpu.VMEM((2, bw * WORD, r), jnp.float32),
+            pltpu.VMEM((2, bw * WORD, r + rp), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
     )(a_bits, x)
-    return out[:c]
+    return out[:c, :r]

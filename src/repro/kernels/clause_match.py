@@ -86,7 +86,7 @@ def clause_match(
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((bb, wv), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),    # streamed by the kernel
+            pl.BlockSpec(memory_space=pl.ANY),    # streamed by the kernel
         ],
         out_specs=pl.BlockSpec((bb, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b + bp, 1), jnp.int32),

@@ -36,7 +36,8 @@ def _kernel(a_ref, m_ref, s_ref, o_ref):
     a = a_ref[...]                       # [BC, BW] uint32
     m = m_ref[...]                       # [1, BW] uint32
     fresh = a & ~m
-    cnt = jax.lax.population_count(fresh).astype(jnp.float32)
+    # Mosaic has no uint32 -> f32 convert; a word's popcount fits int32
+    cnt = jax.lax.population_count(fresh).astype(jnp.int32).astype(jnp.float32)
     # word -> partition segment reduction as one MXU matmul
     o_ref[...] += jnp.dot(cnt, s_ref[...],
                           preferred_element_type=jnp.float32)

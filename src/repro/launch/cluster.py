@@ -90,6 +90,8 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_count"
                                      "=4").strip()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     from repro import api, cluster, obs, stream
 

@@ -202,6 +202,8 @@ def main() -> None:
     ap.add_argument("--out", default="artifacts/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     archs = R.all_archs()
     names = list(archs) if args.arch == "all" else args.arch.split(",")

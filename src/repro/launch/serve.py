@@ -22,6 +22,8 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=128)
     ap.add_argument("--solver", default="optpes")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     from repro import api
 

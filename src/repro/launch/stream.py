@@ -39,6 +39,8 @@ def main() -> None:
                     help="telemetry snapshot directory ('' disables export; "
                          "REPRO_OBS=0 disables the whole plane)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     from repro import api, obs
 

@@ -34,6 +34,8 @@ def main() -> None:
                     choices=["none", "int8", "topk"])
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     from repro.configs import registry as R
     from repro.distributed import mesh_context

@@ -83,12 +83,13 @@ class Span:
     def sync(self, value):
         """Block until `value` is device-ready, folding the wait into
         `sync_ms`; returns `value` so call sites stay expressions."""
+        import jax
         t0 = time.perf_counter()
-        try:
-            import jax
+        # a value holding no JAX array has nothing to wait on; a device
+        # error raised while waiting propagates to the caller
+        if any(isinstance(leaf, jax.Array)
+               for leaf in jax.tree_util.tree_leaves(value)):
             value = jax.block_until_ready(value)
-        except Exception:
-            pass  # non-JAX value (or no runtime) — wall clock still covers it
         self.sync_ms += (time.perf_counter() - t0) * 1e3
         return value
 

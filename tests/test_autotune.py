@@ -4,6 +4,7 @@ layer (a tuned entry must never change results, only speed)."""
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -41,7 +42,7 @@ def test_bucket_from_args_matches_bucket():
 def test_tile_params_miss_and_disable(tmp_path, monkeypatch):
     path = tmp_path / "tiles.json"
     path.write_text(json.dumps({
-        "version": autotune.CACHE_VERSION,
+        "version": autotune.CACHE_VERSION, "backend": jax.default_backend(),
         "entries": {"clause_match|xla|b8_k8_w1":
                     {"strategy": "gemm", "_us": 12.0}}}))
     monkeypatch.setenv(autotune.ENV_VAR, str(path))
@@ -52,6 +53,26 @@ def test_tile_params_miss_and_disable(tmp_path, monkeypatch):
     assert autotune.tile_params("clause_match", "interpret", "b8_k8_w1") == {}
     monkeypatch.setenv(autotune.ENV_VAR, "off")
     assert autotune.tile_params("clause_match", "xla", "b8_k8_w1") == {}
+
+
+def test_cache_timed_on_another_backend_is_ignored(tmp_path, monkeypatch):
+    """A gitignored cache tuned on one host never steers another backend:
+    a TPU run keeps the tiles written in code, whatever a CPU run left."""
+    entry = {"clause_match|xla|b8_k8_w1": {"strategy": "gemm"}}
+    path = tmp_path / "tiles.json"
+    monkeypatch.setenv(autotune.ENV_VAR, str(path))
+    for recorded in ("tpu", None):
+        blob = {"version": autotune.CACHE_VERSION, "entries": entry}
+        if recorded:
+            blob["backend"] = recorded
+        path.write_text(json.dumps(blob))
+        autotune.invalidate()
+        assert autotune.tile_params("clause_match", "xla", "b8_k8_w1") == {}
+    blob["backend"] = jax.default_backend()
+    path.write_text(json.dumps(blob))
+    autotune.invalidate()
+    assert autotune.tile_params("clause_match", "xla", "b8_k8_w1") \
+        == {"strategy": "gemm"}
 
 
 def test_search_writes_picks_from_the_candidate_space(tmp_path):
@@ -104,7 +125,8 @@ def test_autotuned_picks_are_parity_exact(tmp_path, monkeypatch):
     }
     path = tmp_path / "tiles.json"
     path.write_text(json.dumps(
-        {"version": autotune.CACHE_VERSION, "entries": entries}))
+        {"version": autotune.CACHE_VERSION, "backend": jax.default_backend(),
+         "entries": entries}))
     monkeypatch.setenv(autotune.ENV_VAR, str(path))
     autotune.invalidate()
 

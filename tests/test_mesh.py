@@ -206,6 +206,22 @@ assert cold.result.order == fused.result.order
 np.testing.assert_array_equal(np.asarray(cold.result.extra["g_part"]),
                               np.asarray(fused.result.extra["g_part"]))
 print("partitioned-solve-identity OK")
+
+# --- residency: each shard's slices and route-table block on its own device,
+# and the full-width oracle never placed unless serve_reference asks for it
+devs = jax.devices()
+with D.use_mesh(D.shard_mesh()):
+    fleet = pipe.deploy_cluster(n_shards=4, t1_replicas=2)
+    fleet.serve(queries[:64])
+for groups in (fleet.router.t1, fleet.router.t2):
+    for s, group in enumerate(groups):
+        for rep in group:
+            assert rep.postings.devices() == {devs[s]}, (s, rep)
+(table,) = fleet.router._mesh_tables.values()
+for sh in table.tiers.addressable_shards:
+    assert sh.device == devs[sh.index[0].start], sh
+assert fleet._oracle is None
+print("per-device-residency OK")
 print("ALL-MESH-OK")
 """
 

@@ -18,7 +18,8 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.distributed import mesh_context
 from repro.models import moe as M, embedding, egnn as G
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+AUTO = jax.sharding.AxisType.Auto
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
 assert len(jax.devices()) == 8
 
 # --- MoE EP on a real mesh vs oracle
@@ -44,7 +45,7 @@ print("embedding-psum-8dev OK")
 # --- quantized psum across 8 data shards
 from repro.distributed.compression import quantized_psum
 from repro.models.moe import shard_map
-mesh1 = jax.make_mesh((8,), ("data",))
+mesh1 = jax.make_mesh((8,), ("data",), axis_types=(AUTO,))
 v = jax.random.normal(jax.random.key(4), (8, 32))
 exact = v.sum(axis=0)
 got = shard_map(lambda s: quantized_psum(s[0], "data"), mesh1,

@@ -145,6 +145,22 @@ def test_span_sync_passes_through_host_values():
         np.testing.assert_array_equal(arr, [0, 1, 2])
 
 
+def test_span_sync_propagates_device_errors(monkeypatch):
+    """Only values holding no JAX array skip the wait; an error raised
+    while waiting on the device reaches the caller."""
+    import jax
+    import jax.numpy as jnp
+
+    def fail(value):
+        raise RuntimeError("device fault")
+
+    monkeypatch.setattr(jax, "block_until_ready", fail)
+    with obs.span("s") as sp:
+        assert sp.sync({"host": np.arange(2)})["host"].tolist() == [0, 1]
+        with pytest.raises(RuntimeError, match="device fault"):
+            sp.sync({"dev": jnp.arange(2)})
+
+
 def test_event_log_and_cursors():
     obs.event("alpha", x=1)
     seq = obs.EVENTS.seq
