@@ -1,0 +1,6 @@
+"""Host merge (`merge` span) per query answered, ms."""
+import readers
+
+
+def read(run):
+    return readers.span_ms_per_query(run, "merge")
