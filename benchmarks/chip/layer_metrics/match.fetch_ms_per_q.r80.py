@@ -1,0 +1,8 @@
+"""Device-to-host copy (`fetch_ms`) of the match words in the `t1_match`
+and `t2_match` spans per query served, ms."""
+import span_reduce
+
+
+def read(run):
+    return span_reduce.serve_ms_per_query(run,
+                                          span_reduce.match_ms("fetch_ms"))

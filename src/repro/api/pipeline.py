@@ -27,6 +27,7 @@ from typing import Mapping
 
 import numpy as np
 
+from repro import obs
 from repro.core import registry
 from repro.core.config import SolveConfig
 from repro.core.constraint import PartitionedBudget, partition_bounds
@@ -145,6 +146,7 @@ class TieringPipeline:
         split = self.config.budget_split
         return None if isinstance(split, str) else len(split)
 
+    @obs.spanned("solve")
     def solve(self, solver: str = "optpes", budget: float | None = None, *,
               budget_frac: float = 0.5, state: SolverState | None = None,
               config: SolveConfig | None = None, budget_split=None,
@@ -222,6 +224,7 @@ class TieringPipeline:
         self._tiering = None
         return results
 
+    @obs.spanned("solve")
     def refit(self, weights, *, state: SolverState | None = None,
               budget: float | None = None, budget_frac: float | None = None,
               solver: str | None = None, budget_split=_UNSET,
@@ -348,8 +351,9 @@ class TieringPipeline:
                 "tiering to deploy (ψ^flow cannot serve novel queries, paper "
                 "§2.3). Its artifacts are in result.extra['flow'].")
         if self._tiering is None:
-            self._tiering = ClauseTiering.from_selection(
-                self.data, self.result.selected)
+            with obs.span("tiering"):
+                self._tiering = ClauseTiering.from_selection(
+                    self.data, self.result.selected)
         return self._tiering
 
     def coverage(self) -> dict[str, float]:

@@ -103,10 +103,11 @@ class ShardReplica:
             self.shard = shard
         self.draining = False
 
-    def match(self, tokens: jnp.ndarray) -> np.ndarray:
-        """AND-match a padded token batch against the local sub-index."""
+    def match(self, tokens: jnp.ndarray) -> jax.Array:
+        """AND-match a padded token batch against the local sub-index; the
+        match words stay on the device (the caller times wait and copy)."""
         self.account(int(tokens.shape[0]))
-        return np.asarray(matching.match_batch(self.postings, tokens))
+        return matching.match_batch(self.postings, tokens)
 
     def account(self, n_queries: int) -> None:
         """Batch bookkeeping without a local match — the fused mesh path
@@ -296,29 +297,30 @@ class ClusterRouter:
         (`cluster.mesh_serve`: replicated ψ classify, owner-local AND-match
         on the resident slices, psum OR-merge).
         """
-        self.advance_rollout()              # one drain-or-swap phase per batch
-        b = len(queries)
-        if b == 0:
-            return []
-        complete = self.complete_generations()
-        if complete:
-            gen = complete[-1]              # newest fully-covered generation
-            buf, use_t1 = self._buffers[gen], True
-        else:                               # mid-rollout gap: Tier 2 is exact
-            gen, buf, use_t1 = -1, self._fallback_buffer(), False
-            _FALLBACK.inc()
-            obs.event("t2_fallback", corpus_version=buf.corpus_version,
-                      n_queries=b)
-        if buf.w_total and self.stats.full_words_per_query != buf.w_total:
-            # corpus grew (or the served version moved): the saving
-            # denominator follows the version this batch is served at
-            self.stats.full_words_per_query = buf.w_total
-        from repro import distributed
-        plan = distributed.current_plan()
-        cache = self.cache
-        with obs.span("serve", n=b, generation=gen,
-                      corpus_version=buf.corpus_version,
-                      fused=bool(plan.shard_fused)):
+        with obs.span("serve", n=len(queries)) as sp:
+            self.advance_rollout()      # one drain-or-swap phase per batch
+            b = len(queries)
+            if b == 0:
+                return []
+            complete = self.complete_generations()
+            if complete:
+                gen = complete[-1]      # newest fully-covered generation
+                buf, use_t1 = self._buffers[gen], True
+            else:                       # mid-rollout gap: Tier 2 is exact
+                gen, buf, use_t1 = -1, self._fallback_buffer(), False
+                _FALLBACK.inc()
+                obs.event("t2_fallback", corpus_version=buf.corpus_version,
+                          n_queries=b)
+            if buf.w_total and \
+                    self.stats.full_words_per_query != buf.w_total:
+                # corpus grew (or the served version moved): the saving
+                # denominator follows the version this batch is served at
+                self.stats.full_words_per_query = buf.w_total
+            from repro import distributed
+            plan = distributed.current_plan()
+            cache = self.cache
+            sp.set(generation=gen, corpus_version=buf.corpus_version,
+                   fused=bool(plan.shard_fused))
             # -- front-end result cache: after classify-key, before tier
             # match, so the host and fused mesh paths share it. The key is
             # the packed query vocab bitset (the ψ^clause operand): equal
@@ -406,7 +408,8 @@ class ClusterRouter:
                     if not buf.shard_nonempty(s.index):
                         continue            # D₁ misses this shard: no matches
                     rep = self._served(1, s.index, buf)
-                    out[idx1, s.word_lo:s.word_hi] = sp.sync(rep.match(sub))
+                    out[idx1, s.word_lo:s.word_hi] = sp.fetch(
+                        sp.sync(rep.match(sub)))
         idx2 = np.nonzero(~elig)[0]
         if len(idx2):
             sub = jnp.asarray(toks[idx2])
@@ -414,7 +417,8 @@ class ClusterRouter:
                 for s in shards:
                     rep = self._served(2, s.index, buf,
                                        draining_ok=not use_t1)
-                    out[idx2, s.word_lo:s.word_hi] = sp.sync(rep.match(sub))
+                    out[idx2, s.word_lo:s.word_hi] = sp.fetch(
+                        sp.sync(rep.match(sub)))
         return out, np.asarray(elig, bool)
 
     def _match_mesh(self, queries, buf, use_t1, plan
@@ -573,7 +577,9 @@ class TieredCluster:
         from repro import distributed
         dev = mesh_serve.shard_device(distributed.current_plan(),
                                       shard_index, len(self.shards))
-        return jnp.asarray(arr) if dev is None else jax.device_put(arr, dev)
+        with obs.span("upload") as sp:
+            return sp.sync(jnp.asarray(arr) if dev is None
+                           else jax.device_put(arr, dev))
 
     @property
     def postings_t2(self):
@@ -605,8 +611,9 @@ class TieredCluster:
             prev = self.router._buffers[self.router.target_generation]
         posts, words, contents = [], [], []
         for s in self.shards:
-            p, w = shard_mod.shard_tier_postings(
-                self._slices[s.index], s, tiering.tier1_docs)
+            with obs.span("build"):
+                p, w = shard_mod.shard_tier_postings(
+                    self._slices[s.index], s, tiering.tier1_docs)
             posts.append(self._place(p, s.index))
             words.append(w)
             if prev is not None and np.array_equal(
@@ -686,10 +693,12 @@ class TieredCluster:
         m = np.asarray(matching.match_batch(postings, jnp.asarray(toks)))
         return [bitset.np_to_indices(r, n_docs) for r in m]
 
+    @obs.spanned("prepare")
     def prepare_tiering(self, tiering: ClauseTiering) -> ClusterTieringBuffer:
         """Build every shard's next Tier-1 sub-index OFF the request path."""
         return self._build_buffer(tiering, generation=0)
 
+    @obs.spanned("rollout")
     def swap_tiering(self, tiering: ClauseTiering | ClusterTieringBuffer,
                      *, immediate: bool = False) -> int:
         """Start a rolling swap to a new tiering; returns its generation.

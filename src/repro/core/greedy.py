@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.config import SolveConfig
 from repro.core.constraint import as_constraint, resolve_constraint
 from repro.core.problem import SCSKProblem, SolverResult
@@ -81,16 +82,17 @@ def solve_greedy(problem: SCSKProblem, config: SolveConfig,
     order: list[int] = []
     steps = config.max_steps or c
     for _ in range(steps):
-        state, f_val, j, stop = _greedy_step(
-            problem, state, constraint, cost_aware=cost_aware,
-            truncate=truncate)
-        trace.add_evals(2 * c)
-        if bool(stop):
-            break
-        order.append(int(j))
-        trace.on_select(float(f_val), float(state.g_used))
-        if trace.should_stop():
-            break
+        with obs.span("greedy.step") as sp:
+            state, f_val, j, stop = _greedy_step(
+                problem, state, constraint, cost_aware=cost_aware,
+                truncate=truncate)
+            trace.add_evals(2 * c)
+            if bool(sp.sync(stop)):
+                break
+            order.append(int(j))
+            trace.on_select(float(f_val), float(state.g_used))
+            if trace.should_stop():
+                break
     name = "greedy" if cost_aware else "agnostic-dense"
     return trace.result(name, problem, state, order)
 

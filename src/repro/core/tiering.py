@@ -12,6 +12,7 @@ import typing
 
 import numpy as np
 
+from repro import obs
 from repro.core import bitset
 
 if typing.TYPE_CHECKING:  # avoid circular import (data imports core.bitset)
@@ -42,6 +43,7 @@ class ClauseTiering:
                    tier1_docs=t1, vocab_size=data.corpus.vocab_size)
 
     # ψ^clause (eq. 8): Tier 1 iff some selected clause ⊆ q
+    @obs.spanned("classify_log")
     def classify_queries(self, query_bits: np.ndarray, chunk: int = 4096) -> np.ndarray:
         out = np.zeros(query_bits.shape[0], bool)
         if len(self.clauses) == 0:

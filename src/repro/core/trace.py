@@ -24,16 +24,6 @@ from repro.core.config import SolveConfig
 from repro.core.problem import SolverResult
 from repro.core.state import SolverState
 
-_SELECTIONS = obs.counter("solver_selections_total",
-                          "clauses selected across solves",
-                          labels=("solver",))
-_EVALS = obs.counter("solver_evals_total",
-                     "exact (f, g) evaluations across solves",
-                     labels=("solver",))
-_SOLVE_F = obs.gauge("solver_last_f", "last solve's final objective",
-                     labels=("solver",))
-
-
 class Trace:
     def __init__(self, config: SolveConfig, *, f0: float = 0.0,
                  g0: float = 0.0):
@@ -46,9 +36,6 @@ class Trace:
         self.last_f = f0
         self.last_g = g0
         self._t0 = time.perf_counter()
-        # label value cached once: solver name is fixed per Trace and the
-        # counters fire on the per-selection hot path
-        self._solver = str(config.solver)
 
     # -- clock ---------------------------------------------------------------
     def elapsed(self) -> float:
@@ -62,7 +49,6 @@ class Trace:
     # -- recording -----------------------------------------------------------
     def add_evals(self, n: int) -> None:
         self.n_exact_evals += n
-        _EVALS.inc(n, solver=self._solver)
 
     def on_select(self, f_val: float, g_val: float) -> None:
         """Call once per selection with the exact post-selection f/g."""
@@ -70,7 +56,6 @@ class Trace:
         if (self.n_selections % self.config.record_every) == 0:
             self.record()
         self.n_selections += 1
-        _SELECTIONS.inc(solver=self._solver)
         if self.config.on_step is not None:
             self.config.on_step(self)
 
@@ -91,7 +76,6 @@ class Trace:
                 (self.n_selections - 1) % self.config.record_every != 0:
             self.record()
         f_final = float(problem.f_value(state.covered_q))
-        _SOLVE_F.set(f_final, solver=self._solver)
         obs.event("solve_done", solver=name, n_selections=self.n_selections,
                   n_exact_evals=self.n_exact_evals, f_final=f_final,
                   g_final=float(state.g_used),

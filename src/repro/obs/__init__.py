@@ -1,9 +1,10 @@
 """repro.obs — the fleet-wide telemetry plane.
 
-Zero-dependency (numpy + stdlib) observability for every layer of the
-system: a process-global `MetricsRegistry` of typed instruments, a
-`SpanRecorder` for nested request/control-path traces, an `EventLog` for
-discrete control-plane occurrences, and a per-window JSONL exporter.
+Observability for every layer of the system, on numpy, the stdlib and
+JAX's profiler and monitoring hooks: a process-global `MetricsRegistry`
+of typed instruments, a `SpanRecorder` for nested request/control-path
+traces, an `EventLog` for discrete control-plane occurrences, and a
+per-window JSONL exporter.
 
 The module-level singletons (`REGISTRY`, `SPANS`, `EVENTS`) are what the
 instrumented call sites use, via the shortcuts below:
@@ -12,7 +13,10 @@ instrumented call sites use, via the shortcuts below:
     words.inc(n, tier="t1", shard=k)
 
     with obs.span("t1_match", shard=k) as sp:
-        hits = sp.sync(match_batch(...))
+        hits = sp.fetch(sp.sync(match_batch(...)))   # wait, then copy
+
+    @obs.spanned("prune")                  # the whole call is one span
+    def prune_state(...): ...
 
     obs.event("drift_detected", window=i, tv=signal.tv_distance)
 
@@ -33,6 +37,8 @@ depend on it.
 """
 from __future__ import annotations
 
+import functools
+
 from repro.obs import _state
 from repro.obs.events import DEFAULT_EVENT_CAPACITY, EventLog
 from repro.obs.export import DEFAULT_DIR, JsonlExporter, load_dir, read_jsonl
@@ -50,7 +56,7 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "Ring",
     "SpanRecorder", "Span", "NULL_SPAN", "EventLog", "JsonlExporter",
     "SLOEngine", "SLORule", "default_slo_rules", "KernelProfiler",
-    "counter", "gauge", "histogram", "span", "event",
+    "counter", "gauge", "histogram", "span", "spanned", "event",
     "enabled", "disabled", "enable", "disable", "set_enabled",
     "set_exporter", "get_exporter", "export_window", "dashboard", "reset",
     "read_jsonl", "load_dir", "render_line", "fmt_value",
@@ -61,6 +67,7 @@ __all__ = [
 
 REGISTRY = MetricsRegistry()
 SPANS = SpanRecorder()
+SPANS.listen_compiles(lambda: _state.on)
 EVENTS = EventLog()
 SLO = SLOEngine(REGISTRY, EVENTS)
 PROFILER = KernelProfiler(REGISTRY)
@@ -118,6 +125,17 @@ def span(name: str, **attrs):
     if not _state.on:
         return NULL_SPAN
     return SPANS.span(name, **attrs)
+
+
+def spanned(name: str):
+    """Decorator: every call of the function runs inside `span(name)`."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 def event(kind: str, **fields) -> dict | None:

@@ -141,6 +141,7 @@ class TieredEngine:
         return self._live.generation
 
     # -- zero-downtime re-tiering ---------------------------------------------
+    @obs.spanned("prepare")
     def prepare_tiering(self, tiering: ClauseTiering) -> TieringBuffer:
         """Build the next Tier-1 generation OFF the request path.
 
@@ -148,14 +149,18 @@ class TieredEngine:
         and shipping it to device — happens here, against local buffers; the
         live generation keeps serving untouched.
         """
-        postings_t1 = jnp.asarray(
-            matching.tier_postings(self._postings_host, tiering.tier1_docs))
+        with obs.span("build"):
+            host_t1 = matching.tier_postings(self._postings_host,
+                                             tiering.tier1_docs)
+        with obs.span("upload") as sp:
+            postings_t1 = sp.sync(jnp.asarray(host_t1))
         # a production Tier-1 re-indexes with a compacted |D1| doc space:
         # its per-query word traffic is ceil(|D1|/32), not the full W.
         words = bitset.n_words(int(tiering.tier1_docs.sum()))
         return TieringBuffer(tiering=tiering, postings_t1=postings_t1,
                              tier1_words_per_query=words)
 
+    @obs.spanned("rollout")
     def swap_tiering(self, tiering: ClauseTiering | TieringBuffer) -> int:
         """Atomically route traffic to a new tiering; returns the generation.
 
@@ -229,10 +234,10 @@ class TieredEngine:
                 if len(idx) == 0:
                     continue
                 postings = live.postings_t1 if tier == 1 else self.postings_t2
+                sub = jnp.asarray(toks[idx])
                 with obs.span("t1_match" if tier == 1 else "t2_match",
                               n=int(len(idx))) as sp:
-                    m = np.asarray(sp.sync(
-                        matching.match_batch(postings, jnp.asarray(toks[idx]))))
+                    m = sp.fetch(sp.sync(matching.match_batch(postings, sub)))
                 matched.append((idx, m))
                 if tier == 1:
                     self.stats.n_tier1 += len(idx)

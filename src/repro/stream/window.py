@@ -24,6 +24,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import bitset
 from repro.core.problem import SCSKProblem
 from repro.core.state import SolverState
@@ -92,6 +93,7 @@ def check_state_width(problem: SCSKProblem, state: SolverState) -> None:
             f"selection slots vs {problem.n_clauses} clauses")
 
 
+@obs.spanned("prune")
 def prune_state(problem: SCSKProblem, state: SolverState, *,
                 min_unique_mass: float = 0.0,
                 weights: np.ndarray | None = None,

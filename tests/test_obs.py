@@ -161,6 +161,81 @@ def test_span_sync_propagates_device_errors(monkeypatch):
             sp.sync({"dev": jnp.arange(2)})
 
 
+def test_span_clock_cpu_and_fetch():
+    import jax.numpy as jnp
+    with obs.span("root") as a:
+        with obs.span("child") as b:
+            x = b.fetch(b.sync(jnp.arange(6, dtype=jnp.uint32)))
+    assert isinstance(x, np.ndarray) and x.tolist() == list(range(6))
+    child, = obs.SPANS.of_name("child")
+    root, = obs.SPANS.of_name("root")
+    for d in (child, root):
+        assert d["t1_ns"] >= d["t0_ns"] > 0
+        assert d["wall_ms"] == pytest.approx(
+            (d["t1_ns"] - d["t0_ns"]) * 1e-6, abs=1e-4)
+    assert root["t0_ns"] <= child["t0_ns"] <= child["t1_ns"] <= root["t1_ns"]
+    assert child["fetch_bytes"] == 24 and child["fetch_ms"] >= 0.0
+    assert child["sync_ms"] > 0.0
+    # the thread's CPU time, faults and switches: on root spans only
+    rusage = {"cpu_ms", "minflt", "majflt", "nivcsw"}
+    assert rusage <= set(root) and not rusage & set(child)
+    assert 0.0 <= root["cpu_ms"] and root["majflt"] >= 0
+    assert "fetch_bytes" not in root and a.fetch_bytes == 0
+
+
+def test_span_ring_holds_a_serving_window():
+    """More batches than the old 4,096-span ring held, none dropped."""
+    assert obs.DEFAULT_SPAN_CAPACITY >= 2 ** 15
+    pipe = _fresh_pipe()
+    engine = pipe.deploy()
+    obs.reset()
+    queries = pipe.log.queries[:8]
+    for i in range(1100):
+        engine.serve([queries[i % 8]])
+    assert obs.SPANS.seq > 4096
+    assert obs.SPANS.ring.n_dropped == 0
+    assert len(obs.SPANS.of_name("serve")) == 1100
+
+
+def test_spans_enter_profiler_annotations(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("serve") as sp:
+            with obs.span("merge"):
+                sp.sync(jnp.ones(4) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    path, = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+             for f in fs if f.endswith(".xplane.pb")]
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    assert {"repro.serve", "repro.merge"} <= names
+
+
+def test_compile_spans_nest_under_the_open_span():
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda a: a * 3)
+    x = jnp.ones(5)
+    with obs.span("outer") as a:
+        f(x)
+    kids = obs.SPANS.children(a.id)
+    assert [k["name"] for k in kids] == ["compile"]
+    c = kids[0]
+    assert c["depth"] == 1 and c["wall_ms"] > 0.0
+    outer = obs.SPANS.of_name("outer")[0]
+    assert outer["t0_ns"] <= c["t0_ns"] <= c["t1_ns"] <= outer["t1_ns"]
+    seen = obs.SPANS.seq
+    f(x)                                        # cached: no span
+    assert obs.SPANS.seq == seen
+    obs.set_enabled(False)
+    jax.jit(lambda a: a - 1)(x)                 # plane off: nothing
+    assert obs.SPANS.seq == seen
+
+
 def test_event_log_and_cursors():
     obs.event("alpha", x=1)
     seq = obs.EVENTS.seq
@@ -178,6 +253,7 @@ def test_disabled_plane_is_noop():
     with sp as s:
         s.set(x=1)
         assert s.sync("v") == "v"
+        assert s.fetch([1, 2]).tolist() == [1, 2]   # still a host copy
     assert obs.event("nothing") is None
     c = obs.counter("t_off")
     c.inc(5)
@@ -394,6 +470,117 @@ def test_engine_serve_spans_metrics_and_bit_identity():
     assert obs.REGISTRY.total("serve_queries_total") == 64   # no new counts
 
 
+def _tree(spans):
+    """(parent name, name) -> count over `spans`."""
+    import collections
+    by_id = {s["id"]: s for s in spans}
+    return collections.Counter(
+        (by_id[s["parent"]]["name"] if s["parent"] in by_id else None,
+         s["name"]) for s in spans)
+
+
+def test_cluster_serve_span_tree_waits_and_fetches():
+    pipe = _fresh_pipe()
+    fleet = pipe.deploy_cluster(n_shards=2, t1_replicas=1)
+    queries = pipe.log.queries[:64]
+    fleet.serve(queries)                        # compile every shape
+    obs.reset()
+    fleet.serve(queries)
+    spans = obs.SPANS.to_list()
+    assert _tree(spans) == {(None, "serve"): 1, ("serve", "classify"): 1,
+                            ("serve", "t1_match"): 1,
+                            ("serve", "t2_match"): 1, ("serve", "merge"): 1}
+    serve, = obs.SPANS.of_name("serve")
+    assert {"cpu_ms", "minflt", "majflt", "nivcsw"} <= set(serve)
+    words = fleet.router.shards[-1].word_hi     # W over both shards
+    n = 0
+    for name in ("t1_match", "t2_match"):
+        sp, = obs.SPANS.of_name(name)
+        n += sp["n"]
+        assert sp["sync_ms"] > 0.0              # waited on a device array
+        assert sp["fetch_bytes"] == sp["n"] * words * 4
+        assert sp["sync_ms"] + sp["fetch_ms"] <= sp["wall_ms"]
+    assert n == len(queries)
+
+
+def test_engine_serve_fetches_match_words():
+    pipe = _fresh_pipe()
+    engine = pipe.deploy()
+    queries = pipe.log.queries[:64]
+    engine.serve(queries)
+    obs.reset()
+    engine.serve(queries)
+    w = engine.postings_t2.shape[1]
+    for name in ("t1_match", "t2_match"):
+        sp, = obs.SPANS.of_name(name)
+        assert sp["sync_ms"] > 0.0 and sp["fetch_bytes"] == sp["n"] * w * 4
+
+
+def test_forced_recompile_in_t2_match_is_a_compile_child():
+    import jax
+    pipe = _fresh_pipe()
+    fleet = pipe.deploy_cluster(n_shards=1, t1_replicas=1)
+    queries = pipe.log.queries[:64]
+    tier2 = [q for q, e in zip(queries, fleet.classify(queries)) if not e]
+    fleet.serve(tier2)
+    obs.reset()
+    jax.clear_caches()
+    fleet.serve(tier2)
+    t2, = obs.SPANS.of_name("t2_match")
+    kids = obs.SPANS.children(t2["id"])
+    assert kids and {k["name"] for k in kids} == {"compile"}
+    assert sum(k["wall_ms"] for k in kids) <= t2["wall_ms"]
+
+
+def _refit_like_the_benchmark(pipe, fleet, weights):
+    from repro.stream import prune_state
+    state, _, _ = prune_state(pipe.problem, pipe.result.state,
+                              weights=weights, min_unique_mass=1e-3)
+    pipe.refit(weights, state=state)
+    pipe.tiering().classify_queries(pipe.log.query_bits)
+    buf = fleet.prepare_tiering(pipe.tiering())
+    fleet.swap_tiering(buf, immediate=True)
+    fleet.serve(pipe.log.queries[:8])
+
+
+def test_refit_span_tree():
+    pipe = _fresh_pipe()
+    fleet = pipe.deploy_cluster(n_shards=2, t1_replicas=1)
+    rng = np.random.default_rng(1)
+    weights = rng.dirichlet(np.ones(pipe.log.n_queries))
+    obs.reset()
+    _refit_like_the_benchmark(pipe, fleet, weights)
+    spans = obs.SPANS.to_list()
+    tree = _tree(spans)
+    roots = [s["name"] for s in spans if s["parent"] < 0]
+    assert roots == ["prune", "solve", "tiering", "classify_log", "prepare",
+                     "rollout", "serve"]
+    steps = tree[("solve", "greedy.step")]
+    assert steps == len(pipe.result.order) + 1
+    assert tree[("prepare", "build")] == 2      # one per shard
+    assert tree[("prepare", "upload")] == 2
+    assert all(s["sync_ms"] > 0.0 for s in obs.SPANS.of_name("upload"))
+    for name in ("prune", "solve", "tiering", "prepare", "rollout"):
+        assert {"cpu_ms", "nivcsw"} <= set(obs.SPANS.of_name(name)[0])
+
+
+def test_stream_refit_spans_nest_under_refit_and_swap():
+    from repro import stream
+    pipe = _fresh_pipe()
+    fleet = pipe.deploy_cluster(n_shards=2, t1_replicas=1)
+    obs.reset()
+    rep = stream.run_stream(pipe, scenario="rotate", n_windows=3,
+                            queries_per_window=96, seed=0, engine=fleet)
+    assert rep.n_refits >= 1
+    tree = _tree(obs.SPANS.to_list())
+    for pair in [("refit", "prune"), ("refit", "solve"),
+                 ("solve", "greedy.step"), ("refit", "tiering"),
+                 ("refit", "swap"), ("swap", "prepare"),
+                 ("prepare", "build"), ("prepare", "upload"),
+                 ("swap", "rollout")]:
+        assert tree[pair] >= 1, pair
+
+
 def test_cluster_serve_per_shard_counters_and_events():
     pipe = _fresh_pipe()
     fleet = pipe.deploy_cluster(n_shards=2, t1_replicas=2)
@@ -427,7 +614,14 @@ def test_solver_trace_emits_solve_event():
     ev = obs.EVENTS.of_kind("solve_done")
     assert ev and ev[-1]["solver"] == "greedy"
     assert ev[-1]["n_selections"] > 0 and ev[-1]["f_final"] > 0
-    assert obs.REGISTRY.total("solver_selections_total") > 0
+    # one greedy.step span per loop step (the last one finds nothing to
+    # add), each a child of the pipeline's solve span, timing its stop wait
+    solve, = obs.SPANS.of_name("solve")
+    steps = obs.SPANS.of_name("greedy.step")
+    assert len(steps) == ev[-1]["n_selections"] + 1
+    assert all(s["parent"] == solve["id"] for s in steps)
+    assert all(s["sync_ms"] > 0.0 for s in steps)
+    assert sum(s["wall_ms"] for s in steps) <= solve["wall_ms"]
 
 
 # -- kernel profiler (repro.obs.profile) --------------------------------------
