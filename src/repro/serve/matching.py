@@ -16,16 +16,29 @@ import numpy as np
 from repro.core import bitset
 
 
+def _row(postings: jnp.ndarray, token: jnp.ndarray) -> jnp.ndarray:
+    """One postings row as a dynamic slice; a padded slot reads row 0."""
+    return jax.lax.dynamic_slice_in_dim(
+        postings, jnp.maximum(token, 0), 1)[0]
+
+
 @jax.jit
 def match_batch(postings: jnp.ndarray,       # uint32 [V, W]
                 tokens: jnp.ndarray,         # int32 [B, L], -1 padded
                 ) -> jnp.ndarray:            # uint32 [B, W]
-    """AND of postings rows per query; padded slots contribute all-ones."""
-    valid = tokens >= 0
-    rows = postings[jnp.where(valid, tokens, 0)]            # [B, L, W]
-    rows = jnp.where(valid[..., None], rows, jnp.uint32(0xFFFFFFFF))
-    return jax.lax.reduce(rows, jnp.uint32(0xFFFFFFFF),
-                          jax.lax.bitwise_and, (1,))
+    """AND of postings rows per query; padded slots contribute all-ones.
+
+    One term slot at a time, each query's row is fetched as its own
+    dynamic slice (on the TPU a loop of row copies) and ANDed into a
+    [B, W] accumulator. A plain gather of rows wider than 32,768 words
+    would copy the whole postings first.
+    """
+    ones = jnp.uint32(0xFFFFFFFF)
+    out = jnp.full((tokens.shape[0], postings.shape[1]), ones)
+    for slot in range(tokens.shape[1]):
+        rows = jax.vmap(_row, (None, 0))(postings, tokens[:, slot])
+        out &= jnp.where(tokens[:, slot, None] >= 0, rows, ones)
+    return out
 
 
 def tier_postings(postings: np.ndarray, tier1_docs: np.ndarray) -> np.ndarray:

@@ -116,3 +116,42 @@ def test_unseen_query_with_known_clause_is_eligible(tiny_data, tiny_problem):
     got = engine.serve([novel_query])
     want = engine.serve_reference([novel_query])
     np.testing.assert_array_equal(got[0], want[0])
+
+
+# -- match_batch: the row fetch against a numpy AND of the named rows --------
+
+def _and_of_rows(postings: np.ndarray, tokens: np.ndarray) -> np.ndarray:
+    out = np.full((tokens.shape[0], postings.shape[1]), 0xFFFFFFFF, np.uint32)
+    for i, row in enumerate(tokens):
+        for t in row[row >= 0]:
+            out[i] &= postings[t]
+    return out
+
+
+@pytest.mark.parametrize("l", [1, 8])
+@pytest.mark.parametrize("b", [1, 32])
+@pytest.mark.parametrize("w", [1, 128, 32768, 65536])
+def test_match_batch_is_and_of_named_rows(w, b, l):
+    """Bit-identical to an AND of the named postings rows, at widths from
+    one word to a passage shard's 65,536, with -1 padding, an all-padding
+    query and repeated tokens (13 rows repeat tokens within and across
+    queries)."""
+    import jax.numpy as jnp
+    from repro.serve import matching
+    rng = np.random.default_rng(w * 100 + b * 10 + l)
+    v = 13
+    postings = rng.integers(0, 2 ** 32, (v, w), dtype=np.uint32)
+    tokens = rng.integers(0, v, (b, l)).astype(np.int32)
+    lens = rng.integers(1, l + 1, b)
+    tokens[np.arange(l)[None, :] >= lens[:, None]] = -1
+    if b > 1:
+        tokens[0] = -1                         # all padding: all-ones row
+        tokens[1] = tokens[2]                  # one query twice
+    if l > 1:
+        tokens[-1, :2] = tokens[-1, 0]         # one term twice in a query
+    got = np.asarray(matching.match_batch(jnp.asarray(postings),
+                                          jnp.asarray(tokens)))
+    assert got.dtype == np.uint32 and got.shape == (b, w)
+    np.testing.assert_array_equal(got, _and_of_rows(postings, tokens))
+    if b > 1:
+        assert (got[0] == 0xFFFFFFFF).all()

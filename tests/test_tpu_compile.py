@@ -4,8 +4,11 @@ The TPU compiler refuses what interpret mode accepts (unsupported casts,
 blocks off the (8, 128) tiling, programs that overflow HBM), so each
 main-path kernel is compiled here at the widths of the `passage` preset,
 one chip's 2^21-document index, and the four-device fused serve program at
-4 x 2^21 documents. Nothing runs: these tests prove compilation and
-per-device memory, not results or times.
+4 x 2^21 documents. `match_batch` is compiled at passage widths too, and
+must hold no temporary near the postings' size: XLA's gather of 256 KiB
+row slices copied the whole 2 GiB matrix on every call. Nothing runs:
+these tests prove compilation and per-device memory, not results or
+times.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -26,6 +29,7 @@ WQ = 8192 // 32                # packed unique-query width
 C = 1024                       # mined clauses at min_support 1e-3
 B, L = 512, 4                  # serve batch, padded query length
 HBM = 16 * 2 ** 30             # one v5e chip
+MATCH_TEMP = 64 * 2 ** 20      # match_batch's temporaries, at most
 
 
 @pytest.fixture(scope="module")
@@ -60,9 +64,13 @@ def _sds(shape, dtype, sharding):
 def _kernel_cases():
     from repro.kernels import bit_matvec, clause_match, coverage_gain, \
         partition_gain
+    from repro.serve import matching
     bounds = (0, WD // 4, WD // 2, 3 * WD // 4, WD)
-    u32, f32 = jnp.uint32, jnp.float32
-    return {
+    u32, f32, i32 = jnp.uint32, jnp.float32, jnp.int32
+    match = {f"match_batch-{w}-{b}x{l}": (matching.match_batch,
+                                          [((V, w), u32), ((b, l), i32)])
+             for w in (WD, WD // 2) for b, l in ((32, 8), (1, 1))}
+    return match | {
         "clause_match": (clause_match.clause_match,
                          [((B, WV), u32), ((C, WV), u32)]),
         "coverage_gain": (coverage_gain.coverage_gain,
@@ -75,13 +83,19 @@ def _kernel_cases():
     }
 
 
-@pytest.mark.parametrize("op", ["clause_match", "coverage_gain",
-                                "bit_matvec", "partition_gain"])
+@pytest.mark.parametrize("op", [
+    "clause_match", "coverage_gain", "bit_matvec", "partition_gain",
+    f"match_batch-{WD}-32x8", f"match_batch-{WD}-1x1",
+    f"match_batch-{WD // 2}-32x8", f"match_batch-{WD // 2}-1x1"])
 def test_main_path_kernel_compiles_for_v5e(one_chip, op):
     fn, shapes = _kernel_cases()[op]
     args = [_sds(s, d, one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    if op.startswith("match_batch"):    # XLA row fetch, no Pallas kernel
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < MATCH_TEMP, temp
+    else:
+        assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_fused_serve_fits_four_v5e_chips(topo, monkeypatch):
