@@ -381,9 +381,12 @@ class ClusterRouter:
                 self.stats.n_tier1 += sum(1 for _, (e, _r) in hits if e)
             self.stats.n_queries += b
             _CQUERIES.inc(b)
-            with obs.span("merge", n=b):
-                return [bitset.np_to_indices(row, buf.n_docs or self.n_docs)
+            with obs.span("merge", n=b) as sp:
+                rows = [bitset.np_indices_nz(row, buf.n_docs or self.n_docs)
                         for row in out]
+                sp.set(ids=sum(len(ids) for ids, _ in rows),
+                       nz_words=sum(nz for _, nz in rows))
+                return [ids for ids, _ in rows]
 
     def _match_host(self, queries, buf, use_t1
                     ) -> tuple[np.ndarray, np.ndarray]:

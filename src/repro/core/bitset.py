@@ -84,7 +84,41 @@ def np_from_indices(idx: np.ndarray, n_bits: int) -> np.ndarray:
 
 
 def np_to_indices(words: np.ndarray, n_bits: int) -> np.ndarray:
-    return np.nonzero(np_unpack(words, n_bits))[-1]
+    """Sorted int64 positions < n_bits of the set bits, along the last axis
+    (row after row): `np.nonzero(np_unpack(words, n_bits))[-1]`."""
+    return np_indices_nz(words, n_bits)[0]
+
+
+def np_indices_nz(words: np.ndarray, n_bits: int) -> tuple[np.ndarray, int]:
+    """(`np_to_indices(words, n_bits)`, number of nonzero uint32 words).
+
+    Match rows are mostly zero words, so the set bits are found by reading
+    the words as uint64 pairs (uint32 when their count is odd) and
+    unpacking only the nonzero units. A row with more than half its units
+    nonzero is unpacked whole instead: there that costs less than placing
+    every bit by its unit's offset.
+    """
+    words = np.ascontiguousarray(words, dtype="<u4")
+    w = words.shape[-1]
+    flat = words.reshape(-1)
+    units = flat.view("<u8") if flat.size % 2 == 0 else flat
+    shift = 6 if units.itemsize == 8 else 5         # log2 of bits a unit
+    at = np.flatnonzero(units)
+    if 2 * at.size > units.size:
+        nz = np.count_nonzero(flat)
+        ids = np.flatnonzero(np.unpackbits(
+            flat.view(np.uint8), bitorder="little").view(bool))
+    else:
+        sel = units[at]
+        nz = np.count_nonzero(sel.view("<u4"))
+        ids = np.flatnonzero(np.unpackbits(
+            sel.view(np.uint8), bitorder="little").view(bool))
+        ids += ((at - np.arange(at.size)) << shift)[ids >> shift]
+    if words.ndim > 1:
+        ids %= WORD * w
+    if n_bits < WORD * w:
+        ids = ids[ids < n_bits]
+    return ids, int(nz)
 
 
 def np_popcount(words: np.ndarray) -> np.ndarray:

@@ -40,8 +40,8 @@ from jax.profiler import TraceAnnotation
 from repro.obs.ring import Ring
 
 # holds a traced benchmark window of spans with room to spare (a 51 s
-# serving window at 240 q/s finishes about 5,300)
-DEFAULT_SPAN_CAPACITY = 1 << 15
+# serving window at 240 q/s is at most 12,240 serve calls of 4-5 spans)
+DEFAULT_SPAN_CAPACITY = 1 << 17
 
 # JAX times the persistent-cache lookup inside this event, so it fires
 # once for every program compiled or loaded from the cache

@@ -41,6 +41,63 @@ def test_from_indices_and_to_indices():
         bitset.np_to_indices(expected, 96), np.unique(idx))
 
 
+def _row(density: str, n_bits: int, seed: int) -> np.ndarray:
+    """Words of `n_bits` bits laid out by `density`, with stray bits set
+    past `n_bits` in the last word wherever it has room for them."""
+    w = bitset.n_words(n_bits)
+    rng = np.random.default_rng(seed)
+    words = np.zeros(w, np.uint32)
+    if density == "bit_0":
+        words[0] = 1
+    elif density == "bit_last":
+        words[-1] = np.uint32(1) << np.uint32((n_bits - 1) % 32)
+    elif density == "sparse":
+        words[rng.choice(w, max(1, w // 50), replace=False)] = rng.integers(
+            1, 2**32, max(1, w // 50), dtype=np.uint64).astype(np.uint32)
+    elif density == "every_other":
+        words[::2] = rng.integers(1, 2**32, len(words[::2]), dtype=np.uint64
+                                  ).astype(np.uint32)
+    elif density == "ones":
+        words[:] = 0xFFFFFFFF
+    elif density.startswith("units_"):
+        # k nonzero units (uint64 pairs for an even word count, words for
+        # an odd one): the two sides of the whole-row unpack's switch
+        pair = 2 if w % 2 == 0 else 1
+        units = w // pair
+        k = units // 2 + int(density[len("units_"):])
+        # the last unit is one of them: the stray bits below land there
+        chosen = rng.choice(units - 1, k - 1, replace=False)
+        for u in np.r_[chosen, units - 1]:
+            words[u * pair + rng.integers(pair)] = rng.integers(
+                1, 2**32, dtype=np.uint64)
+    else:
+        assert density == "empty"
+    if n_bits % 32 and density != "empty":
+        words[-1] |= np.uint32(0xFFFFFFFF) << np.uint32(n_bits % 32)
+    return words
+
+
+@pytest.mark.parametrize("n_bits", [96, 95, 127, 128, 2**16 + 5, 2**16])
+@pytest.mark.parametrize("density", ["empty", "bit_0", "bit_last", "sparse",
+                                     "every_other", "ones", "units_0",
+                                     "units_1"])
+def test_np_to_indices_matches_full_unpack(density, n_bits):
+    words = _row(density, n_bits, seed=n_bits)
+    expected = np.nonzero(bitset.np_unpack(words, n_bits))[-1]
+    got = bitset.np_to_indices(words, n_bits)
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(got, expected)
+    assert (np.diff(got) > 0).all()
+    ids, nz = bitset.np_indices_nz(words, n_bits)
+    np.testing.assert_array_equal(ids, expected)
+    assert nz == np.count_nonzero(words)
+    # a stack of rows reads row after row, as np.nonzero does
+    stack = np.stack([words, words[::-1]])
+    np.testing.assert_array_equal(bitset.np_to_indices(stack, n_bits),
+                                  np.nonzero(bitset.np_unpack(stack,
+                                                              n_bits))[-1])
+
+
 def test_from_indices_with_validity_mask():
     idx = jnp.asarray([5, 17, 40, 0, 0])
     valid = jnp.asarray([True, True, True, False, False])

@@ -184,8 +184,10 @@ def test_span_clock_cpu_and_fetch():
 
 
 def test_span_ring_holds_a_serving_window():
-    """More batches than the old 4,096-span ring held, none dropped."""
-    assert obs.DEFAULT_SPAN_CAPACITY >= 2 ** 15
+    """More batches than the old 4,096-span ring held, none dropped; the
+    ring holds a 51 s window at 240 q/s served one query a call, at five
+    spans a call."""
+    assert obs.DEFAULT_SPAN_CAPACITY >= 5 * 240 * 51
     pipe = _fresh_pipe()
     engine = pipe.deploy()
     obs.reset()
@@ -501,6 +503,22 @@ def test_cluster_serve_span_tree_waits_and_fetches():
         assert sp["fetch_bytes"] == sp["n"] * words * 4
         assert sp["sync_ms"] + sp["fetch_ms"] <= sp["wall_ms"]
     assert n == len(queries)
+
+
+def test_cluster_merge_span_counts_ids_and_nonzero_words():
+    pipe = _fresh_pipe()
+    fleet = pipe.deploy_cluster(n_shards=2, t1_replicas=1)
+    queries = pipe.log.queries[:64]
+    answers = fleet.serve(queries)
+    merges = obs.SPANS.of_name("merge")
+    assert merges and sum(sp["n"] for sp in merges) == len(queries)
+    words = fleet.router.shards[-1].word_hi     # W over both shards
+    for sp in merges:
+        assert 0 <= sp["nz_words"] <= sp["n"] * words
+    assert sum(sp["ids"] for sp in merges) == sum(len(a) for a in answers)
+    # a nonzero word of a row is a word that holds one of its answer's ids
+    assert sum(sp["nz_words"] for sp in merges) == sum(
+        len(np.unique(np.asarray(a) >> 5)) for a in answers) > 0
 
 
 def test_engine_serve_fetches_match_words():
